@@ -46,10 +46,9 @@ import numpy as np
 from repro.config import SageConfig, get_config
 from repro.data.synthetic import ShapesDataset
 from repro.kernels.dispatch import DISPATCH_LOG
-from repro.models import dit
-from repro.models import text_encoder as te
+from repro.launch.compile_cache import enable_compile_cache
+from repro.serving import engine as engine_lib
 from repro.serving import reports
-from repro.serving.engine import SageServingEngine
 from repro.serving.faults import FaultPlan
 from repro.serving.policies import (PadAwarePolicy, SaturationAdmission,
                                     make_cache_admission)
@@ -58,19 +57,16 @@ from repro.serving.trunk_cache import TrunkCache
 
 
 def build_engine(args):
-    cfg = get_config("sage-dit", smoke=True)
+    preset = args.preset or ("full" if jax.default_backend() == "tpu"
+                             else "smoke")
+    cfg = get_config("sage-dit", smoke=preset == "smoke")
     sage = SageConfig(total_steps=args.steps, share_ratio=0.3,
                       guidance_scale=4.0, tau_min=0.3,
                       adaptive_branch=args.adaptive,
                       shared_uncond_cfg=args.shared_uncond,
                       sampler=args.sampler)
-    tc = te.text_cfg(dim=cfg.cond_dim, layers=2)
-    return SageServingEngine(
-        cfg, sage,
-        dit_params=dit.init_params(cfg, jax.random.PRNGKey(0)),
-        text_params=te.init_text(jax.random.PRNGKey(1), tc),
-        text_cfg=tc, group_size=4,
-        attn_impl=args.backend,
+    return engine_lib.build_engine(
+        cfg, sage, group_size=4, attn_impl=args.backend,
         step_impl="fused" if args.fused_step else None)
 
 
@@ -251,7 +247,10 @@ def run_streaming(engine, prompts, args):
             slice_steps=args.slice_steps,
             max_groups_per_tick=args.max_groups_per_tick,
             n_params=engine.cfg.n_params(),
-            n_tokens=(engine.cfg.latent_size // engine.cfg.patch) ** 2)
+            n_tokens=(engine.cfg.latent_size // engine.cfg.patch) ** 2,
+            # the roofline floor needs a chip's peaks; a CPU run has none
+            device_kind=(jax.devices()[0].device_kind
+                         if jax.default_backend() == "tpu" else None))
         print(reports.format_report(slo, cap,
                                     reports.dispatch_report()))
 
@@ -371,8 +370,13 @@ def main():
     ap.add_argument("--report", action="store_true",
                     help="print the joined SLO/capacity/dispatch report "
                          "(streaming mode)")
+    ap.add_argument("--preset", choices=["smoke", "full"], default=None,
+                    help="sage-dit preset: the 2-layer smoke model or the "
+                         "published widths (default: full on TPU, smoke "
+                         "elsewhere)")
     ap.add_argument("--seed", type=int, default=0)
     args = ap.parse_args()
+    enable_compile_cache()
 
     engine = build_engine(args)
     ds = ShapesDataset(res=16)

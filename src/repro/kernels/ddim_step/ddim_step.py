@@ -9,18 +9,21 @@ Per sampler step SAGE (like any CFG diffusion sampler) computes
 Unfused, that is 3 elementwise passes over 3 latent-sized tensors (z,
 eps_u, eps_c) -> 5 HBM round trips.  The kernel computes z' in one pass:
 read 3 tiles, write 1.  Latents are flattened to (rows, lanes) tiles
-(lane dim a multiple of 128 for the VPU); the 5 step scalars ride in a
-(1, 8)-padded block mapped to every grid point.
+(lane dim a multiple of 128 for the VPU); the step scalars ride in an
+8-wide f32 row per scalar set.
 
 Two launch shapes share the same kernel body:
 
 * :func:`ddim_step_2d` — whole batch as one (rows, lanes) grid, ONE
   scalar row broadcast to every tile (per-group execution: all rows sit
   at the same grid position);
-* :func:`ddim_step_rows` — (B, rows, lanes) grid with a (B, 8) scalar
-  block indexed by the batch grid axis, so every row carries its OWN
-  (a_t, s_t, a_n, s_n) — the packed serving path, where one super-batch
-  mixes groups at different positions on the DDIM grid.
+* :func:`ddim_step_rows` — (B, rows, lanes) grid with the whole (B, 8)
+  scalar table resident in SMEM, read at row ``program_id(0)``, so every
+  row carries its OWN (a_t, s_t, a_n, s_n) — the packed serving path,
+  where one super-batch mixes groups at different positions on the DDIM
+  grid.  (A per-row (1, 8) VMEM block would break Mosaic's tiling rule:
+  a block's last two dims must be multiples of (8, 128) or span the
+  array.)
 
 VMEM budget: 4 tiles x block(256, 256) x 4B = 1 MB  << 16 MB/core.
 """
@@ -31,16 +34,18 @@ import functools
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
 BLOCK_R = 256
 BLOCK_C = 256
 
 
-def _kernel(scal_ref, z_ref, eu_ref, ec_ref, out_ref):
-    w = scal_ref[0, 0]
-    a_t, s_t = scal_ref[0, 1], scal_ref[0, 2]
-    a_n, s_n = scal_ref[0, 3], scal_ref[0, 4]
-    clip = scal_ref[0, 5]
+def _kernel(scal_ref, z_ref, eu_ref, ec_ref, out_ref, *, per_row=False):
+    r = pl.program_id(0) if per_row else 0
+    w = scal_ref[r, 0]
+    a_t, s_t = scal_ref[r, 1], scal_ref[r, 2]
+    a_n, s_n = scal_ref[r, 3], scal_ref[r, 4]
+    clip = scal_ref[r, 5]
     z = z_ref[...].astype(jnp.float32)
     eu = eu_ref[...].astype(jnp.float32)
     ec = ec_ref[...].astype(jnp.float32)
@@ -76,13 +81,13 @@ def ddim_step_rows(scalars, z, eps_u, eps_c, block_r: int,
     R % block_r == 0 and C % BLOCK_C == 0; scalars (B, 8) f32, one
     [guidance, a_t, s_t, a_n, s_n, clip_x0, 0, 0] row per batch element.
     Same kernel body as :func:`ddim_step_2d` — the batch grid axis selects
-    both the latent tile and its scalar row."""
+    both the latent tile and its scalar row of the SMEM table."""
     B, R, C = z.shape
     grid = (B, R // block_r, C // BLOCK_C)
     tile = pl.BlockSpec((1, block_r, BLOCK_C), lambda b, i, j: (b, i, j))
-    scal = pl.BlockSpec((1, 8), lambda b, i, j: (b, 0))
+    scal = pl.BlockSpec(memory_space=pltpu.SMEM)
     return pl.pallas_call(
-        _kernel,
+        functools.partial(_kernel, per_row=True),
         grid=grid,
         in_specs=[scal, tile, tile, tile],
         out_specs=tile,

@@ -7,6 +7,8 @@ back afterwards); a full-stack compute + select would not be bitwise-safe
 against the per-group oracle — see the note in ddim_step/ops.py."""
 from __future__ import annotations
 
+import jax.numpy as jnp
+
 from repro.kernels._tiles import (per_row_scalars, row_block, scalar_block,
                                   scalar_rows, tile_2d, tile_rows)
 from repro.kernels.dpmpp_step.dpmpp_step import (BLOCK_C, BLOCK_R,
@@ -39,8 +41,11 @@ def fused_cfg_dpmpp_step(z, eps_u, eps_c, eps_prev, guidance,
         from repro.kernels.dispatch import resolve_interpret
         interpret = resolve_interpret()
     # layout must match the kernel's scal_ref reads (see dpmpp_step.py)
+    # expm1(-h) from the same f32 lambdas the kernel reads (h = lam_n - lam)
+    em1 = jnp.expm1(-(jnp.asarray(lam_n, jnp.float32)
+                      - jnp.asarray(lam, jnp.float32)))
     values = (guidance, a_t, s_t, a_n, s_n, clip_x0,
-              lam, lam_p, lam_n, is_first)
+              lam, lam_p, lam_n, is_first, em1)
     if per_row_scalars(*values):
         br = row_block(z[0].size, BLOCK_C, BLOCK_R)
         tiles, untile = tile_rows(br, BLOCK_C, z, eps_u, eps_c, eps_prev)

@@ -1,15 +1,15 @@
-"""Import-safe roofline cost model (TPU v5e hardware constants).
+"""Import-safe roofline cost model (per-chip peaks keyed by device kind).
 
 ``launch/dryrun.py`` owns the *measured* roofline (lower + compile every
 (arch x shape) on the production mesh and read XLA's cost analysis), but
 importing it has a deliberate side effect: it forces
 ``--xla_force_host_platform_device_count=512`` into ``XLA_FLAGS`` before
 JAX initialises, which is exactly wrong for anything that is not a
-dry-run.  This module holds the shared hardware constants and the small
+dry-run.  This module holds the shared per-chip peak table and the small
 closed-form predictors that the serving telemetry reports
 (``serving/reports.py``) need, with no JAX import and no environment
-mutation; ``dryrun.py`` imports the constants back from here so there is
-a single source of truth.
+mutation; ``dryrun.py`` reads its peaks from here so there is a single
+source of truth.
 
 The predictors are deliberately first-order: they model the scheduler's
 *tick economics* (segments per phase, rows per launch, NFE ledger), not
@@ -23,11 +23,32 @@ from __future__ import annotations
 from dataclasses import dataclass
 
 # ---------------------------------------------------------------------------
-# TPU v5e hardware model (roofline constants; chips = mesh size)
+# Per-chip peaks, keyed by ``jax.Device.device_kind``
 # ---------------------------------------------------------------------------
-PEAK_FLOPS = 197e12          # bf16 FLOP/s per chip
-HBM_BW = 819e9               # B/s per chip
-ICI_BW = 50e9                # B/s per link (counted once per op byte)
+@dataclass(frozen=True)
+class ChipPeaks:
+    """Published peaks of ONE chip."""
+    flops: float              # bf16 FLOP/s
+    hbm_bw: float             # HBM B/s
+    ici_bw: float             # B/s per interconnect link
+
+
+#: Source: Google Cloud documentation, "TPU v5e" (197 TFLOP/s bf16,
+#: HBM at 819 GB/s, 1,600 Gbit/s chip-to-chip interconnect over 4
+#: links).  Add a kind only with its published source.
+PEAKS = {
+    "TPU v5 lite": ChipPeaks(flops=197e12, hbm_bw=819e9, ici_bw=50e9),
+}
+
+
+def chip_peaks(device_kind: str) -> ChipPeaks:
+    """Peaks for a ``device_kind``; an unknown kind raises (never a
+    default — a roofline against the wrong chip is a wrong number)."""
+    try:
+        return PEAKS[device_kind]
+    except KeyError:
+        raise KeyError(f"no peak table entry for device kind "
+                       f"{device_kind!r}; known: {sorted(PEAKS)}") from None
 
 
 def denoiser_flops_per_eval(n_params: float, n_tokens: int) -> float:
@@ -40,12 +61,12 @@ def denoiser_flops_per_eval(n_params: float, n_tokens: int) -> float:
     return 2.0 * n_params * 2 * n_tokens
 
 
-def roofline_seconds(flops: float, bytes_acc: float = 0.0,
+def roofline_seconds(peaks: ChipPeaks, flops: float, bytes_acc: float = 0.0,
                      coll_bytes: float = 0.0, chips: int = 1) -> float:
     """Lower-bound wall seconds: the max of the three roofline terms."""
     c = max(chips, 1)
-    return max(flops / c / PEAK_FLOPS, bytes_acc / c / HBM_BW,
-               coll_bytes / ICI_BW)
+    return max(flops / c / peaks.flops, bytes_acc / c / peaks.hbm_bw,
+               coll_bytes / peaks.ici_bw)
 
 
 def _ceil_div(a: int, b: int) -> int:

@@ -24,9 +24,12 @@ from repro.config import SHAPES, get_config, list_archs
 from repro.launch.mesh import make_production_mesh
 from repro.launch.specs import build_case
 
-# TPU v5e roofline constants live in the import-safe repro.launch.costs
-# (importing *this* module mutates XLA_FLAGS; reports must not pay that)
-from repro.launch.costs import HBM_BW, ICI_BW, PEAK_FLOPS  # noqa: E402
+# per-chip peaks live in the import-safe repro.launch.costs (importing
+# *this* module mutates XLA_FLAGS; reports must not pay that).  The
+# production mesh is made of v5e chips.
+from repro.launch.costs import chip_peaks  # noqa: E402
+
+V5E = chip_peaks("TPU v5 lite")
 
 _DTYPE_BYTES = {"f64": 8, "f32": 4, "f16": 2, "bf16": 2, "s64": 8, "u64": 8,
                 "s32": 4, "u32": 4, "s16": 2, "u16": 2, "s8": 1, "u8": 1,
@@ -170,9 +173,9 @@ def run_case(arch: str, shape_name: str, multi_pod: bool, smoke: bool = False,
         "full_scan_compile_s": round(t_full, 2),
         "flops_per_dev": flops, "bytes_per_dev": bytes_acc,
         "collective_bytes_per_dev": coll,
-        "compute_term_s": flops / PEAK_FLOPS,
-        "memory_term_s": bytes_acc / HBM_BW,
-        "collective_term_s": coll["total"] / ICI_BW,
+        "compute_term_s": flops / V5E.flops,
+        "memory_term_s": bytes_acc / V5E.hbm_bw,
+        "collective_term_s": coll["total"] / V5E.ici_bw,
         "model_flops_global": model_flops,
         "useful_flops_ratio": (model_flops / (flops * n_chips)
                                if flops else 0.0),

@@ -27,14 +27,18 @@ from __future__ import annotations
 
 from typing import List, Optional, Sequence
 
+import jax
+
 from repro.config import ModelConfig, SageConfig
 from repro.config import replace as config_replace
 from repro.core.schedule import Schedule, make_schedule
+from repro.models import dit
+from repro.models import text_encoder as te
 from repro.serving.scheduler import Completed, RequestScheduler
 from repro.serving.telemetry import MetricsRegistry, Tracer
 from repro.serving.trunk_cache import TrunkCache
 
-__all__ = ["Completed", "SageServingEngine"]
+__all__ = ["Completed", "SageServingEngine", "build_engine"]
 
 
 class SageServingEngine:
@@ -124,3 +128,18 @@ class SageServingEngine:
     @property
     def cost_saving(self) -> float:
         return self.scheduler.cost_saving
+
+
+def build_engine(cfg: ModelConfig, sage: SageConfig, *, seed: int = 0,
+                 dit_params=None, **kw) -> SageServingEngine:
+    """An engine over random weights drawn from ``seed``: the DiT from
+    ``PRNGKey(seed)`` (unless ``dit_params`` is given) and a 2-layer text
+    tower at ``cfg.cond_dim`` from ``PRNGKey(seed + 1)``.  ``kw`` forwards
+    to :class:`SageServingEngine` (``group_size``, ``attn_impl``, ...)."""
+    tc = te.text_cfg(dim=cfg.cond_dim, layers=2)
+    if dit_params is None:
+        dit_params = dit.init_params(cfg, jax.random.PRNGKey(seed))
+    return SageServingEngine(
+        cfg, sage, dit_params=dit_params,
+        text_params=te.init_text(jax.random.PRNGKey(seed + 1), tc),
+        text_cfg=tc, **kw)

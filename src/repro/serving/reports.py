@@ -35,8 +35,8 @@ from __future__ import annotations
 
 from typing import Any, Dict, List, Mapping, Optional
 
-from repro.launch.costs import (denoiser_flops_per_eval, predict_drain,
-                                roofline_seconds)
+from repro.launch.costs import (chip_peaks, denoiser_flops_per_eval,
+                                predict_drain, roofline_seconds)
 from repro.serving.telemetry import safe_ratio
 
 __all__ = ["slo_report", "capacity_report", "attributed_columns",
@@ -128,11 +128,14 @@ def capacity_report(summary: Mapping[str, Any], *, total_steps: int,
                     max_groups_per_tick: Optional[int] = None,
                     n_params: Optional[float] = None,
                     n_tokens: int = 0,
-                    chips: int = 1) -> Dict[str, Any]:
+                    chips: int = 1,
+                    device_kind: Optional[str] = None) -> Dict[str, Any]:
     """Predicted vs. observed tick economics (the dryrun cost model wired
     to the scheduler).  ``n_params``/``n_tokens`` (the DiT's analytic
-    parameter count and latent token count) add a roofline seconds-per-
-    request floor; omit them for the tick-economics-only report."""
+    parameter count and latent token count) plus ``device_kind`` (the
+    chip the floor is for, a ``launch.costs.PEAKS`` key — unknown kinds
+    raise) add a roofline seconds-per-request floor; omit them for the
+    tick-economics-only report."""
     from repro.core.shared_sampling import phase_split
     n_shared, _ = phase_split(total_steps, share_ratio)
     requests = int(summary.get("requests", 0))
@@ -180,11 +183,13 @@ def capacity_report(summary: Mapping[str, Any], *, total_steps: int,
             "stalled_ticks": summary.get("stalled_ticks", 0),
         },
     }
-    if n_params and n_tokens:
+    if n_params and n_tokens and device_kind is not None:
         flops_eval = denoiser_flops_per_eval(n_params, n_tokens)
         rep["roofline"] = {
+            "device_kind": device_kind,
             "flops_per_eval": flops_eval,
             "seconds_per_request_floor": roofline_seconds(
+                chip_peaks(device_kind),
                 flops_eval * safe_ratio(obs_nfe or pred.nfe,
                                         max(requests, 1)),
                 chips=chips),

@@ -127,6 +127,7 @@ so even an *enabled* tracer is output-invisible.
 """
 from __future__ import annotations
 
+import functools
 import time
 from collections import deque
 from dataclasses import dataclass
@@ -493,8 +494,8 @@ class RequestScheduler:
                 s.shared_uncond_cfg, self.sched.T)
 
     # -- jit-bucketed segment runners -----------------------------------
-    def _eps_fn(self):
-        params, cfg = self.dit_params, self.cfg
+    def _eps_fn(self, params):
+        cfg = self.cfg
         return lambda z, t, c: dit.forward(params, cfg, z, t, c)
 
     def _runner_cfg(self, samplers):
@@ -506,30 +507,39 @@ class RequestScheduler:
         return self.sage, tuple(samplers)
 
     def _shared_runner(self, n_steps: int, samplers):
+        """Jitted shared-phase segment with the DiT params bound as a
+        traced argument: a closure would embed them in the executable as
+        constants (gigabytes at published widths)."""
         key = ("shared", n_steps, samplers)
         if key not in self._runners:
-            eps_fn, sched = self._eps_fn(), self.sched
+            sched = self.sched
             sage, rs = self._runner_cfg(samplers)
 
             @jax.jit
-            def run(carry, cbar, null, grid):
-                return shared_phase(eps_fn, sched, sage, carry, cbar, null,
-                                    n_steps, grid=grid, row_samplers=rs)
-            self._runners[key] = run
+            def shared_segment(params, carry, cbar, null, grid):
+                return shared_phase(self._eps_fn(params), sched, sage, carry,
+                                    cbar, null, n_steps, grid=grid,
+                                    row_samplers=rs)
+            self._runners[key] = functools.partial(shared_segment,
+                                                   self.dit_params)
         return self._runners[key]
 
     def _branch_runner(self, n_steps: int, samplers):
+        """Jitted branch-phase segment; params bound as in
+        :meth:`_shared_runner`."""
         key = ("branch", n_steps, samplers)
         if key not in self._runners:
-            eps_fn, sched = self._eps_fn(), self.sched
+            sched = self.sched
             sage, rs = self._runner_cfg(samplers)
 
             @jax.jit
-            def run(carry, cond_flat, mask, null, fork_idx, grid):
-                return branch_phase(eps_fn, sched, sage, carry, cond_flat,
-                                    mask, null, n_steps, fork_idx,
+            def branch_segment(params, carry, cond_flat, mask, null,
+                               fork_idx, grid):
+                return branch_phase(self._eps_fn(params), sched, sage, carry,
+                                    cond_flat, mask, null, n_steps, fork_idx,
                                     grid=grid, row_samplers=rs)
-            self._runners[key] = run
+            self._runners[key] = functools.partial(branch_segment,
+                                                   self.dit_params)
         return self._runners[key]
 
     # -- submission & admission -----------------------------------------

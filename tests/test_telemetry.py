@@ -16,7 +16,7 @@ import pytest
 
 from repro.config import SageConfig, get_config
 from repro.kernels import dispatch
-from repro.launch.costs import predict_drain
+from repro.launch.costs import chip_peaks, predict_drain, roofline_seconds
 from repro.models import dit
 from repro.models import text_encoder as te
 from repro.serving import reports
@@ -341,7 +341,8 @@ def test_reports_join_and_render(chaos_run):
     cap = reports.capacity_report(
         s, total_steps=6, share_ratio=0.33, group_size=4, slice_steps=3,
         max_groups_per_tick=2, n_params=CFG.n_params(),
-        n_tokens=(CFG.latent_size // CFG.patch) ** 2)
+        n_tokens=(CFG.latent_size // CFG.patch) ** 2,
+        device_kind="TPU v5 lite")
     assert cap["predicted"]["ticks_to_drain"] > 0
     assert cap["observed"]["ticks"] == s["ticks"]
     assert (cap["gaps"]["extra_ticks"]
@@ -365,3 +366,14 @@ def test_predict_drain_tick_economics():
     assert capped.ticks == 9                 # 3 waves of 2 groups
     empty = predict_drain(0, 4, 8, 2, 4)
     assert empty.ticks == 0 and empty.nfe == 0
+
+
+def test_chip_peaks_unknown_kind_raises():
+    """The peak table never assumes a chip: an unknown ``device_kind``
+    (the CPU backend's included) raises instead of defaulting to v5e."""
+    v5e = chip_peaks("TPU v5 lite")
+    assert v5e.flops == 197e12 and v5e.hbm_bw == 819e9
+    assert roofline_seconds(v5e, 197e12) == 1.0
+    for kind in ("cpu", "TPU v4", ""):
+        with pytest.raises(KeyError, match="no peak table entry"):
+            chip_peaks(kind)
