@@ -27,7 +27,8 @@ injects seeded faults (``launch=P,miss=P,corrupt=P,stall=P,seed=N``):
 
 Telemetry (streaming mode): ``--trace out.json`` records the full
 request/group/exec lifecycle as Chrome trace-event JSON (load in
-Perfetto or chrome://tracing — deterministic under the virtual clock),
+Perfetto or chrome://tracing; request and group lanes on the virtual
+tick clock, the exec lane's host spans in real seconds),
 ``--metrics out.prom`` writes the Prometheus exposition of every
 counter/gauge/histogram plus the live kernel-dispatch fallback matrix,
 and ``--report`` prints the joined SLO + capacity (dryrun cost model) +

@@ -118,9 +118,11 @@ Observability (``serving.telemetry``): the stats dicts are
 view over registry-owned state; pass ``metrics=`` to share a registry
 with the export path), and an optional
 :class:`~repro.serving.telemetry.Tracer` receives lifecycle spans for
-every request/group transition plus per-tick phase spans.  Emission is
-clocked by the same injectable ``now``, so virtual-time traces are
-deterministic; with ``tracer=None`` (default) every emit site is a
+every request/group transition (stamped with the injectable ``now``)
+plus exec spans of the host's work — tick phases, pack build/scatter,
+segment dispatch, text-tower dispatch/wait, completion fetch — stamped
+by the tracer's own clock and mirrored as ``sage.*`` profiler
+annotations.  With ``tracer=None`` (default) every emit site is a
 single ``is not None`` branch and runs are bitwise-identical to the
 pre-telemetry scheduler — tracing never touches RNG or sampler inputs,
 so even an *enabled* tracer is output-invisible.
@@ -461,13 +463,23 @@ class RequestScheduler:
 
     # -- embedding ------------------------------------------------------
     def _embed(self, prompts: Sequence[str]):
+        tr = self.tracer
+        if tr is not None:
+            # host lowering + dispatch of the (eager) text tower
+            tr.begin("submit.dispatch", n=len(prompts))
         toks = te.tokenize(prompts, max_len=self.cfg.cond_len)
         feats, pooled = te.encode_text(self.text_params, self.text_cfg, toks)
         # project per-token features to the DiT cond width if needed
         if feats.shape[-1] != self.cfg.cond_dim:
             reps = -(-self.cfg.cond_dim // feats.shape[-1])
             feats = jnp.tile(feats, (1, 1, reps))[..., :self.cfg.cond_dim]
-        return np.asarray(feats), np.asarray(pooled)
+        if tr is not None:
+            tr.end()
+            tr.begin("submit.wait", n=len(prompts))   # device + copy
+        out = np.asarray(feats), np.asarray(pooled)
+        if tr is not None:
+            tr.end()
+        return out
 
     @property
     def _latent_shape(self) -> Tuple[int, int, int]:
@@ -878,6 +890,9 @@ class RequestScheduler:
             tr.instant("group.launch", now, pid=PID_GROUPS, tid=g.gid,
                        n=N, beta=g.beta, n_shared=g.n_shared, qos=g.qos,
                        cache_hit=g.cache_hit, state=g.state)
+            for r in g.members:
+                tr.span("request.queue", r.t_arrival, now - r.t_arrival,
+                        pid=PID_REQUESTS, tid=r.rid, gid=g.gid)
         self.open_groups.remove(g)
         self.inflight.append(g)
 
@@ -895,28 +910,33 @@ class RequestScheduler:
                                 pid=PID_GROUPS, tid=g.gid,
                                 stored=bool(stored))
 
-    def _count_launch(self, rows: int, pad_rows: int,
-                      phase: str = "", n_steps: int = 0,
-                      groups: int = 1, shape=None) -> None:
+    def _dispatch(self, phase: str, runner, args: Tuple, rows: int,
+                  pad_rows: int, n_steps: int, groups: int = 1,
+                  shape=None):
         """THE segment-launch choke point: every denoiser dispatch —
-        packed bucket or per-group — lands here exactly once, so the
-        stats ledger and the trace's ``phase.*`` launch spans can never
-        disagree (the reconciliation test pins spans == launches)."""
+        packed bucket or per-group — runs here exactly once, inside its
+        ``phase.<phase>`` span, so the stats ledger and the trace's
+        launch spans can never disagree (the reconciliation test pins
+        spans == launches).  Returns the runner's output carry."""
+        tr = self.tracer
+        skey = "x".join(map(str, shape)) if shape else None
+        if tr is not None:
+            tr.begin(f"phase.{phase}")
+        out = runner(*args)
+        if tr is not None:
+            kw = {"shape": skey} if skey is not None else {}
+            tr.end(rows=rows, pad_rows=pad_rows, n_steps=n_steps,
+                   groups=groups, **kw)
         self.stats["launches"] += 1
         self.stats["pack_rows"] += rows
         self.stats["pack_pad_rows"] += pad_rows
-        skey = "x".join(map(str, shape)) if shape else None
         if skey is not None:
             d = self.shape_stats.setdefault(
                 skey, {"launches": 0, "rows": 0, "pad_rows": 0})
             d["launches"] += 1
             d["rows"] += rows
             d["pad_rows"] += pad_rows
-        if self.tracer is not None and phase:
-            kw = {"shape": skey} if skey is not None else {}
-            self.tracer.launch_span(f"phase.{phase}", rows=rows,
-                                    pad_rows=pad_rows, n_steps=n_steps,
-                                    groups=groups, **kw)
+        return out
 
     def _after_segment(self, g: _Group, s: int) -> None:
         """Post-advance accounting + phase transitions, shared by the
@@ -952,17 +972,16 @@ class RequestScheduler:
         grid = packing.pack_grid([g], self.sched.T)
         if g.state == "shared":
             s = min(self.slice_steps, g.n_shared - g.steps_done)
-            g.carry = self._shared_runner(s, g.sampler)(
-                g.carry, g.cbar, null, grid)
-            self._count_launch(1, 0, phase="shared", n_steps=s,
-                               shape=g.shape)
+            g.carry = self._dispatch(
+                "shared", self._shared_runner(s, g.sampler),
+                (g.carry, g.cbar, null, grid), 1, 0, n_steps=s,
+                shape=g.shape)
         else:
             s = min(self.slice_steps, g.total_steps - g.steps_done)
-            g.carry = self._branch_runner(s, g.sampler)(
-                g.carry, g.cond_flat, g.mask, null, jnp.int32(g.n_shared),
-                grid)
-            self._count_launch(len(g.members), 0, phase="branch",
-                               n_steps=s, shape=g.shape)
+            g.carry = self._dispatch(
+                "branch", self._branch_runner(s, g.sampler),
+                (g.carry, g.cond_flat, g.mask, null, jnp.int32(g.n_shared),
+                 grid), len(g.members), 0, n_steps=s, shape=g.shape)
         self._after_segment(g, s)
         g.retries = 0
         return True
@@ -992,6 +1011,7 @@ class RequestScheduler:
         null = self._null_cond()
         seg_len: Dict[int, int] = {}
         failed: List[_Group] = []
+        tr = self.tracer
         for key, groups in packing.build_packs(
                 todo, self.slice_steps if slice_steps is None else
                 slice_steps, mix_samplers=self.mix_samplers,
@@ -999,37 +1019,44 @@ class RequestScheduler:
             s = key.n_steps
             if self.faults is not None and self.faults.launch_fails():
                 self.stats["launch_faults"] += 1
-                if self.tracer is not None:
-                    self.tracer.exec_mark(
-                        "launch.fault", phase=key.phase,
-                        groups=len(groups))
+                if tr is not None:
+                    tr.exec_mark("launch.fault", phase=key.phase,
+                                 groups=len(groups))
                 failed.extend(groups)
                 continue
+            if tr is not None:
+                tr.begin("pack.build", phase=key.phase)
             if key.phase == "shared":
                 carry, cbar = packing.pack_shared(groups)
                 rs = packing.pack_samplers(groups)
-                samplers = rs if rs is not None else groups[0].sampler
                 grid = packing.pack_grid(groups, self.sched.T)
-                out = self._shared_runner(s, samplers)(carry, cbar, null,
-                                                       grid)
-                packing.unpack_shared(out, groups)
-                self._count_launch(len(groups), 0, phase="shared",
-                                   n_steps=s, groups=len(groups),
-                                   shape=key.shape)
+                runner = self._shared_runner(
+                    s, rs if rs is not None else groups[0].sampler)
+                args = (carry, cbar, null, grid)
+                rows, pads = len(groups), 0
             else:
                 carry, cond, mask, fork = packing.pack_branch(
                     groups, self.group_size)
                 rs = packing.pack_samplers(groups, self.group_size)
-                samplers = rs if rs is not None else groups[0].sampler
                 grid = packing.pack_grid(groups, self.sched.T,
                                          self.group_size)
-                out = self._branch_runner(s, samplers)(carry, cond, mask,
-                                                       null, fork, grid)
-                packing.unpack_branch(out, groups, self.group_size)
+                runner = self._branch_runner(
+                    s, rs if rs is not None else groups[0].sampler)
+                args = (carry, cond, mask, null, fork, grid)
                 rows, pads = packing.pad_stats(groups, self.group_size)
-                self._count_launch(rows, pads, phase="branch",
-                                   n_steps=s, groups=len(groups),
-                                   shape=key.shape)
+            if tr is not None:
+                tr.end()
+            out = self._dispatch(key.phase, runner, args, rows, pads,
+                                 n_steps=s, groups=len(groups),
+                                 shape=key.shape)
+            if tr is not None:
+                tr.begin("pack.scatter", phase=key.phase)
+            if key.phase == "shared":
+                packing.unpack_shared(out, groups)
+            else:
+                packing.unpack_branch(out, groups, self.group_size)
+            if tr is not None:
+                tr.end()
             for g in groups:
                 seg_len[g.gid] = s
         for g in todo:
@@ -1081,10 +1108,14 @@ class RequestScheduler:
 
     def _complete(self, g: _Group, now: float,
                   record_latency: bool = True) -> List[Completed]:
+        tr = self.tracer
+        if tr is not None:
+            tr.begin("complete.fetch", rows=len(g.members))
         imgs = self._decode(g.carry.z)
+        if tr is not None:
+            tr.end()
         self.stats["nfe"] += g.nfe
         self.stats["completed"] += len(g.members)
-        tr = self.tracer
         done = []
         for i, r in enumerate(g.members):
             # per-REQUEST status: a degraded (tier-downgraded) request
@@ -1284,7 +1315,7 @@ class RequestScheduler:
         self._tick_now = now
         tr = self.tracer
         if tr is not None:
-            tr.tick_begin(now, self.ticks)
+            tr.tick_begin(self.ticks)
         # arrival-process EWMA (requests per tick) — feeds admission
         # decisions and the adaptive pad-aware hold budget
         self._arrival_rate = (0.5 * self._arrivals_since_tick

@@ -1,5 +1,5 @@
 """Serving-tier observability: request-lifecycle tracing + a metrics
-registry — zero-overhead when disabled, deterministic under virtual time.
+registry — zero-overhead when disabled.
 
 Seven PRs of serving machinery (scheduler -> policies -> packing -> trunk
 cache -> faults -> kernels) report through one end-of-run ``summary()``
@@ -10,20 +10,19 @@ primitives that answer those questions without perturbing anything:
 
 :class:`Tracer`
     Structured lifecycle spans (``request.submit -> request.admit ->
-    request.group -> group.hold -> group.launch -> cache.{exact,ann,miss}
-    -> phase.shared -> group.fork -> phase.branch -> request.complete`` /
-    ``group.preempt`` / ``group.resume`` — see docs/architecture.md §11
-    for the full taxonomy) plus per-tick phase-timing spans, exportable
-    as Chrome trace-event JSON (loadable in Perfetto / chrome://tracing).
-    Timestamps derive ONLY from the scheduler's injectable ``now`` clock,
-    so a virtual-time trace is a pure function of the arrival trace —
-    byte-identical across runs and machines.  Events *within* one tick
-    are laid out on a deterministic sub-tick slot cursor (1/1024 tick per
-    event) so Perfetto renders admission -> launch -> advance -> complete
-    as properly nested spans without wall-clock data.  The tracer records
-    its own cumulative emit time (``self_seconds``) so the overhead
-    contract (< 5% of run wall time) is testable without flaky A/B
-    timing.
+    request.group -> group.hold -> group.launch -> request.queue ->
+    cache.{exact,ann,miss} -> phase.shared -> group.fork -> phase.branch
+    -> request.complete`` / ``group.preempt`` / ``group.resume`` — see
+    docs/architecture.md §11 for the full taxonomy) plus exec-lane spans
+    of the host's own work (the tick and its admit / launch / advance /
+    complete phases, pack build and scatter, each segment dispatch, the
+    text tower's dispatch and wait, the completion fetch), exportable as
+    Chrome trace-event JSON (loadable in Perfetto / chrome://tracing).
+    Lifecycle timestamps are the scheduler's injectable ``now``; exec
+    spans read the tracer's own ``clock`` (``time.perf_counter`` unless
+    a test injects one) and are also written into the JAX profiler's
+    trace as ``sage.<name>`` annotations, so device-idle time can be
+    laid against the host phase that caused it.
 
 :class:`MetricsRegistry`
     The single home of the serving stats: counters live in
@@ -37,10 +36,11 @@ primitives that answer those questions without perturbing anything:
     ``examples/serve_shared.py``); naming is ``sage_<group>_<key>`` with
     ``_total`` suffixed to counters.
 
-Neither primitive touches jax, RNG streams, or any value the sampler
-sees: tracing enabled or disabled is bitwise-invisible to results (the
-conformance goldens pin this), and with ``tracer=None`` (the default)
-the scheduler's emit sites reduce to one ``is not None`` branch.
+Neither primitive touches a device value, an RNG stream, or anything
+the sampler sees: tracing enabled or disabled is bitwise-invisible to
+results (``tests/test_telemetry.py`` pins this), and with
+``tracer=None`` (the default) the scheduler's emit sites reduce to one
+``is not None`` branch: no clock read, no annotation.
 
 :func:`safe_ratio` is the shared divide-by-zero guard for every derived
 rate (``launches_per_tick``, ``pad_waste``, hit rates): zero-tick /
@@ -56,6 +56,8 @@ from collections import Counter, OrderedDict
 from dataclasses import dataclass, field
 from typing import (Any, Callable, Dict, Iterable, List, Mapping,
                     Optional, Sequence, Tuple)
+
+from jax.profiler import TraceAnnotation
 
 __all__ = ["safe_ratio", "Histogram", "StatGroup", "MetricsRegistry",
            "Tracer", "TraceEvent", "LATENCY_BUCKETS",
@@ -302,18 +304,10 @@ PID_REQUESTS, PID_GROUPS, PID_EXEC = 1, 2, 3
 _PROCESS_NAMES = {PID_REQUESTS: "requests", PID_GROUPS: "groups",
                   PID_EXEC: "exec"}
 
-# sub-tick layout: each exec-lane event occupies one slot of 1/1024
-# tick, so phase spans nest their launches and the whole tick stays
-# inside [now, now+1) no matter how busy it was (the cursor clamps at
-# the last slot — ordering beyond 1022 events/tick piles up, it never
-# spills into the next tick)
-_SLOT = 1.0 / 1024.0
-_MAX_SLOT = 1022
-
 
 @dataclass
 class TraceEvent:
-    """One trace event in scheduler-clock units (unscaled)."""
+    """One trace event in clock units (seconds; unscaled)."""
     name: str
     cat: str
     ph: str                       # "X" complete span | "i" instant
@@ -326,46 +320,50 @@ class TraceEvent:
 
 @dataclass
 class Tracer:
-    """Collects lifecycle spans; exports Chrome trace-event JSON.
+    """Collects lifecycle and exec spans; exports Chrome trace-event JSON.
 
-    ``time_scale`` maps scheduler-clock units to microseconds at export
-    (default 1e6: one virtual tick renders as one second — readable in
-    Perfetto; pass 1.0 when driving with wall-clock seconds... which
-    already are microseconds after the 1e6 scale, so leave the default).
-    ``max_events`` bounds memory on long-lived servers: past it events
-    are dropped (counted in ``dropped``) while ``counts()`` stays exact.
+    Two clocks.  Request- and group-lane events (``instant`` / ``span``)
+    carry the scheduler's ``now`` as given.  Exec-lane spans (``begin`` /
+    ``end`` and the tick frame built on them) read ``clock`` at both
+    ends, and while open each is also a ``jax.profiler.TraceAnnotation``
+    named ``sage.<name>``, so a profile shows the host's tick phases
+    beside the device's ops.  A caller that drives ``now`` from
+    ``clock`` (the default ``time.perf_counter``) gets one timeline;
+    tests pass a fake ``clock`` for a deterministic trace.
 
-    Overhead accounting: every emit is wrapped in a perf_counter pair
-    whose total lands in ``self_seconds`` — the tracer's own cost is
-    part of its telemetry, so the < 5% overhead contract is asserted
-    directly instead of via flaky A/B wall comparisons.
+    Every event names its cause: lifecycle events sit on the lane of
+    their request (tid=rid) or group (tid=gid), exec events carry the
+    ``tick`` they ran in (absent outside ``tick()``, e.g. in ``submit``).
+
+    ``time_scale`` maps clock units to microseconds at export (seconds
+    -> us by default).  ``max_events`` bounds memory on long-lived
+    servers: past it events are dropped (counted in ``dropped``) while
+    ``counts()`` stays exact.
     """
+    clock: Callable[[], float] = time.perf_counter
     time_scale: float = 1e6
     max_events: int = 1 << 20
     events: List[TraceEvent] = field(default_factory=list)
     dropped: int = 0
-    self_seconds: float = 0.0
 
     def __post_init__(self):
         self._counts: Counter = Counter()
-        self._base = 0.0              # current tick's ts origin
-        self._slot = 0                # sub-tick slot cursor
-        self._tick_args: Dict[str, Any] = {}
-        self._phase: Optional[str] = None
-        self._phase_slot = 0
+        # open exec spans, innermost last: (name, cat, start, annotation,
+        # args)
+        self._open: List[Tuple[str, str, float, Any, Dict[str, Any]]] = []
+        self._tick: Optional[int] = None
+        self._phase = False
 
     # -- core emit -------------------------------------------------------
     def _emit(self, name: str, cat: str, ph: str, ts: float, dur: float,
               pid: int, tid: int,
               args: Optional[Dict[str, Any]]) -> None:
-        t0 = time.perf_counter()
         self._counts[name] += 1
         if len(self.events) < self.max_events:
             self.events.append(
                 TraceEvent(name, cat, ph, ts, dur, pid, tid, args))
         else:
             self.dropped += 1
-        self.self_seconds += time.perf_counter() - t0
 
     def instant(self, name: str, ts: float, *, pid: int, tid: int,
                 cat: str = "lifecycle", **args: Any) -> None:
@@ -378,61 +376,57 @@ class Tracer:
         """A duration span at explicit scheduler-clock bounds."""
         self._emit(name, cat, "X", ts, dur, pid, tid, args or None)
 
-    # -- exec-lane tick structure ---------------------------------------
-    def _cursor(self) -> int:
-        s = self._slot
-        if self._slot < _MAX_SLOT:
-            self._slot += 1
-        return s
+    # -- exec lane --------------------------------------------------------
+    def begin(self, name: str, cat: str = "exec", **args: Any) -> None:
+        """Open an exec-lane span (and its ``sage.<name>`` annotation);
+        spans nest, and ``end`` closes the innermost."""
+        ann = TraceAnnotation(f"sage.{name}")
+        ann.__enter__()
+        self._open.append((name, cat, self.clock(), ann, args))
 
-    def tick_begin(self, now: float, tick: int) -> None:
-        """Open a tick frame: subsequent exec-lane events lay out on the
-        sub-tick slot cursor starting at ``now``."""
-        self._base = float(now)
-        self._slot = 0
-        self._phase = None
-        self._tick_args = {"tick": tick}
+    def end(self, **args: Any) -> None:
+        """Close the innermost open exec span; ``args`` join those given
+        to ``begin``."""
+        name, cat, t0, ann, a = self._open.pop()
+        t1 = self.clock()
+        ann.__exit__(None, None, None)
+        a.update(args)
+        if self._tick is not None:
+            a["tick"] = self._tick
+        self._emit(name, cat, "X", t0, t1 - t0, PID_EXEC, 0, a)
+
+    def exec_mark(self, name: str, **args: Any) -> None:
+        """Instant on the exec lane, now."""
+        if self._tick is not None:
+            args["tick"] = self._tick
+        self._emit(name, "exec", "i", self.clock(), 0.0, PID_EXEC, 0,
+                   args or None)
+
+    def tick_begin(self, tick: int) -> None:
+        """Open tick ``tick``'s frame: the ``tick`` span, parent of every
+        exec event until ``tick_end``."""
+        self._tick = tick
+        self.begin("tick", cat="tick")
 
     def phase_begin(self, name: str) -> None:
         """Open a tick phase (closing any still-open one first, so the
         scheduler's admit -> launch -> advance -> complete sections each
         call only ``phase_begin``)."""
         self.phase_end()
-        self._phase = name
-        self._phase_slot = self._slot
+        self.begin(f"tick.{name}", cat="tick")
+        self._phase = True
 
     def phase_end(self) -> None:
-        """Close the open tick phase as a span covering every slot its
-        events consumed (at least one, so empty phases stay visible)."""
-        if self._phase is None:
-            return
-        start = self._phase_slot
-        end = max(self._slot, start + 1)
-        self._slot = end
-        self._emit(f"tick.{self._phase}", "tick", "X",
-                   self._base + start * _SLOT, (end - start) * _SLOT,
-                   PID_EXEC, 0, None)
-        self._phase = None
-
-    def exec_mark(self, name: str, **args: Any) -> None:
-        """Instant on the exec lane at the next sub-tick slot."""
-        self._emit(name, "exec", "i", self._base + self._cursor() * _SLOT,
-                   0.0, PID_EXEC, 0, args or None)
-
-    def launch_span(self, name: str, **args: Any) -> None:
-        """One segment launch: a one-slot span on the exec lane, nested
-        inside the current tick phase."""
-        self._emit(name, "exec", "X",
-                   self._base + self._cursor() * _SLOT, _SLOT,
-                   PID_EXEC, 0, args or None)
+        """Close the open tick phase, if any."""
+        if self._phase:
+            self._phase = False
+            self.end()
 
     def tick_end(self, **args: Any) -> None:
-        """Close the tick frame as a span over all consumed slots."""
+        """Close the open phase and the tick frame."""
         self.phase_end()
-        a = dict(self._tick_args)
-        a.update(args)
-        self._emit("tick", "tick", "X", self._base,
-                   max(self._slot, 1) * _SLOT, PID_EXEC, 0, a)
+        self.end(**args)
+        self._tick = None
 
     # -- views & export --------------------------------------------------
     def counts(self) -> Dict[str, int]:

@@ -1,9 +1,10 @@
-"""Serving telemetry: tracer schema + reconciliation, metrics registry,
-dispatch attribution, safe_ratio, and the zero-perturbation contract.
+"""Serving telemetry: tracer schema + reconciliation, exec spans on an
+injected clock, metrics registry, dispatch attribution, safe_ratio, and
+the zero-perturbation contract.
 
 The expensive scenario (an overloaded, faulted, cached streaming run with
 telemetry enabled) runs ONCE at module scope; the schema, conservation,
-reconciliation, export and overhead tests all read that single run.  The
+reconciliation and export tests all read that single run.  The
 bitwise-identity test drives the same short trace twice — tracer and
 registry on vs. off — and pins byte-equal latents and identical
 summaries, the observability layer's core contract.
@@ -53,7 +54,6 @@ def _themed_prompts(n, themes=3, seed=0):
 
 @pytest.fixture(scope="module")
 def chaos_run():
-    import time
     tracer = Tracer()
     metrics = MetricsRegistry()
     cache = TrunkCache(tau_trunk=0.9)
@@ -65,7 +65,6 @@ def chaos_run():
     prompts = _themed_prompts(20)
     rng = np.random.RandomState(3)
     arrival = np.cumsum(rng.exponential(0.4, len(prompts)))
-    t0 = time.perf_counter()
     done, now, i = [], 0.0, 0
     ticks = 0
     while (i < len(prompts) or sched.pending) and ticks < 200:
@@ -84,14 +83,13 @@ def chaos_run():
             if batch[half:]:
                 sched.submit(batch[half:], now=now, qos="batch")
         done.extend(sched.tick(now=now))
-    wall = time.perf_counter() - t0
-    return sched, tracer, metrics, done, wall
+    return sched, tracer, metrics, done
 
 
 def test_trace_schema_well_formed(chaos_run):
     """Every exported event: known phase, lane, non-negative duration,
     instants carry a scope, spans a dur."""
-    _, tracer, _, _, _ = chaos_run
+    _, tracer, _, _ = chaos_run
     obj = tracer.to_chrome()
     assert obj["traceEvents"], "chaos run must produce events"
     for e in obj["traceEvents"]:
@@ -110,7 +108,7 @@ def test_trace_schema_well_formed(chaos_run):
 def test_request_conservation(chaos_run):
     """Every submitted request is accounted for exactly once across the
     span set: completes + sheds + rejects + pending == submits."""
-    sched, tracer, _, _, _ = chaos_run
+    sched, tracer, _, _ = chaos_run
     c = tracer.counts()
     assert c["request.submit"] == 20
     accounted = (c.get("request.complete", 0)
@@ -125,7 +123,7 @@ def test_spans_reconcile_with_summary(chaos_run):
     """Exact agreement between trace-side counts and the summary()
     ledger: launches, completions, sheds, cache hits per tier,
     preemptions (the ISSUE acceptance bar)."""
-    sched, tracer, _, done, _ = chaos_run
+    sched, tracer, _, done = chaos_run
     c, s = tracer.counts(), sched.summary()
     assert (c.get("phase.shared", 0) + c.get("phase.branch", 0)
             == s["launches"])
@@ -152,7 +150,7 @@ def test_spans_reconcile_with_summary(chaos_run):
 
 
 def test_chrome_export_round_trips(chaos_run, tmp_path):
-    _, tracer, _, _, _ = chaos_run
+    _, tracer, _, _ = chaos_run
     path = tmp_path / "trace.json"
     n = tracer.export(str(path))
     obj = json.loads(path.read_text())
@@ -160,17 +158,8 @@ def test_chrome_export_round_trips(chaos_run, tmp_path):
     assert obj["otherData"]["dropped_events"] == 0
 
 
-def test_tracer_overhead_under_5pct(chaos_run):
-    """The tracer accounts its own emit cost; it must stay under 5% of
-    the run's wall time (the zero-overhead-when-disabled layer must be
-    near-zero-overhead when enabled too)."""
-    _, tracer, _, _, wall = chaos_run
-    assert tracer.self_seconds < 0.05 * wall, (
-        f"tracer spent {tracer.self_seconds:.4f}s of {wall:.2f}s wall")
-
-
 def test_prometheus_export(chaos_run, tmp_path):
-    sched, _, metrics, _, _ = chaos_run
+    sched, _, metrics, _ = chaos_run
     text = metrics.to_prometheus()
     s = sched.summary()
     assert f"sage_scheduler_launches_total {int(s['launches'])}" in text
@@ -202,12 +191,32 @@ def test_metrics_registry_claims_names_once():
 # zero-perturbation: telemetry on == telemetry off, bitwise
 # ---------------------------------------------------------------------------
 
-def _short_run(telemetry):
-    tracer = Tracer() if telemetry else None
+#: the spans a traced run of the tick loop emits where the host works
+PROGRAM_SPANS = ("tick", "tick.admit", "tick.launch", "tick.advance",
+                 "tick.complete", "submit.dispatch", "submit.wait",
+                 "request.queue", "pack.build", "pack.scatter",
+                 "phase.shared", "phase.branch", "complete.fetch")
+
+
+class FakeClock:
+    """A clock that advances one unit per read and counts its reads."""
+
+    def __init__(self):
+        self.reads = 0
+
+    def __call__(self) -> float:
+        self.reads += 1
+        return float(self.reads)
+
+
+def _short_run(telemetry, packed=True, tracer=None):
+    if telemetry and tracer is None:
+        tracer = Tracer()
     metrics = MetricsRegistry() if telemetry else None
     sched = _engine().streaming_scheduler(
         slice_steps=3, max_wait_ticks=1, trunk_cache=TrunkCache(
-            tau_trunk=0.9), tracer=tracer, metrics=metrics)
+            tau_trunk=0.9), tracer=tracer if telemetry else None,
+        metrics=metrics, packed=packed)
     prompts = _themed_prompts(8, seed=5)
     done, now = [], 0.0
     sched.submit(prompts[:4], now=now)
@@ -221,16 +230,104 @@ def _short_run(telemetry):
     return sched.summary(), sorted(done, key=lambda c: c.prompt)
 
 
-def test_telemetry_is_bitwise_invisible():
-    """Identical latents and summary with tracing+registry on vs. off:
-    the layer observes the tick loop, it never perturbs it."""
-    s_off, done_off = _short_run(telemetry=False)
-    s_on, done_on = _short_run(telemetry=True)
+def _assert_invisible(packed):
+    s_off, done_off = _short_run(telemetry=False, packed=packed)
+    tracer = Tracer()
+    s_on, done_on = _short_run(telemetry=True, packed=packed, tracer=tracer)
     assert len(done_off) == len(done_on) == 8
     for a, b in zip(done_off, done_on):
         assert a.prompt == b.prompt
         np.testing.assert_array_equal(a.image, b.image)
+        assert a.nfe_share == b.nfe_share
     assert s_off == s_on
+    return tracer
+
+
+def test_telemetry_is_bitwise_invisible():
+    """Identical latents, NFE and summary with tracing+registry on vs.
+    off, every exec span included: the layer observes the tick loop, it
+    never perturbs it."""
+    counts = _assert_invisible(packed=True).counts()
+    missing = [n for n in PROGRAM_SPANS if not counts.get(n)]
+    assert not missing, f"the traced run emitted no {missing}"
+
+
+def test_telemetry_is_bitwise_invisible_per_group():
+    """The same contract on the one-launch-per-group path, whose
+    dispatches run inside their ``phase.*`` spans too."""
+    counts = _assert_invisible(packed=False).counts()
+    s = counts.get("phase.shared", 0) + counts.get("phase.branch", 0)
+    assert s > 0 and "pack.build" not in counts
+
+
+def test_tracer_off_reads_no_clock(monkeypatch):
+    """``tracer=None``: no emit site reads a clock or builds a profiler
+    annotation (the scheduler is driven with explicit ``now``)."""
+    import types
+
+    from repro.serving import scheduler as scheduler_mod
+    from repro.serving import telemetry
+    calls = []
+
+    def counted(name):
+        return lambda *a, **k: calls.append(name) or 0.0
+
+    fake_time = types.SimpleNamespace(
+        perf_counter=counted("perf_counter"), monotonic=counted("monotonic"),
+        time=counted("time"))
+    monkeypatch.setattr(scheduler_mod, "time", fake_time)
+    monkeypatch.setattr(telemetry, "TraceAnnotation", counted("annotation"))
+    _, done = _short_run(telemetry=False)
+    assert len(done) == 8 and calls == []
+
+
+def test_exec_spans_on_an_injected_clock():
+    """Exec spans read the tracer's clock twice each (once per instant),
+    nest tick -> phase -> pack/dispatch in clock order, and name the tick
+    that caused them; lifecycle events keep the scheduler's ``now``."""
+    clock = FakeClock()
+    tracer = Tracer(clock=clock)
+    _short_run(telemetry=True, tracer=tracer)
+    spans = [e for e in tracer.events if e.pid == 3 and e.ph == "X"]
+    marks = [e for e in tracer.events if e.pid == 3 and e.ph == "i"]
+    assert clock.reads == 2 * len(spans) + len(marks) > 0
+    assert all(e.dur > 0 and e.ts == int(e.ts) for e in spans)
+    ticks = {e.args["tick"]: e for e in spans if e.name == "tick"}
+    for e in spans:
+        if e.name.startswith("submit."):
+            assert "tick" not in e.args and e.args["n"] == 4
+            continue
+        t = ticks[e.args["tick"]]
+        assert t.ts <= e.ts and e.ts + e.dur <= t.ts + t.dur, e
+    # within a tick the phases run in order, each pack build before its
+    # dispatch and each scatter after it
+    for tick in ticks:
+        names = [e.name for e in sorted(spans, key=lambda e: e.ts)
+                 if e.args.get("tick") == tick and e.name != "tick"]
+        phases = [n for n in names if n.startswith("tick.")]
+        assert phases == ["tick.admit", "tick.launch", "tick.advance",
+                          "tick.complete"]
+        inner = [n for n in names if not n.startswith("tick.")]
+        for i, n in enumerate(inner):
+            if n.startswith("phase."):
+                assert inner[i - 1] == "pack.build"
+                assert inner[i + 1] == "pack.scatter"
+    lifecycle = [e for e in tracer.events if e.pid != 3]
+    assert {e.ts for e in lifecycle} <= {float(t) for t in range(21)}
+
+
+def test_request_queue_spans_join_arrival_to_launch():
+    """One ``request.queue`` span per launched member, on its request's
+    lane, from its arrival to its group's ``group.launch``."""
+    tracer = Tracer(clock=FakeClock())
+    _short_run(telemetry=True, tracer=tracer)
+    launch = {e.tid: e for e in tracer.events if e.name == "group.launch"}
+    submit = {e.tid: e for e in tracer.events if e.name == "request.submit"}
+    queue = [e for e in tracer.events if e.name == "request.queue"]
+    assert len(queue) == sum(e.args["n"] for e in launch.values()) == 8
+    for e in queue:
+        assert e.pid == 1 and e.ts == submit[e.tid].ts
+        assert e.ts + e.dur == launch[e.args["gid"]].ts
 
 
 # ---------------------------------------------------------------------------
@@ -330,7 +427,7 @@ def test_dispatch_log_disabled_records_nothing():
 # ---------------------------------------------------------------------------
 
 def test_reports_join_and_render(chaos_run):
-    sched, tracer, _, _, _ = chaos_run
+    sched, tracer, _, _ = chaos_run
     s = sched.summary()
     slo = reports.slo_report(s, counts=tracer.counts(),
                              pending=sched.pending)
