@@ -11,6 +11,7 @@ the proxy metric is meaningful offline (no pretrained CLIP available).
 """
 from __future__ import annotations
 
+import functools
 from typing import Any, Dict, Tuple
 
 import jax
@@ -44,18 +45,37 @@ def init_text(key, cfg: ModelConfig) -> Params:
             "ln_f": jnp.zeros((cfg.d_model,))}
 
 
+def _block(x: jax.Array, bp: Params, cfg: ModelConfig,
+           causal: bool = True) -> Tuple[jax.Array, None]:
+    """One pre-norm tower block, as a ``lax.scan`` body over stacked params."""
+    x = x + attn.gqa_full(bp["attn"], cfg, rms_norm(x, bp["ln1"]),
+                          causal=causal)
+    x = x + apply_mlp(bp["mlp"], rms_norm(x, bp["ln2"]), cfg.mlp_kind)
+    return x, None
+
+
+@functools.partial(jax.jit, static_argnums=1)
+def text_stack(blocks: Params, cfg: ModelConfig, x: jax.Array) -> jax.Array:
+    """The text tower's layer stack, compiled once per (cfg, x shape).
+
+    A scan built eagerly around a fresh closure is re-traced and re-lowered
+    on every call; this one jit is reused.  Only the stack is jitted: the
+    eager ops around it in :func:`encode_text` keep their numerics."""
+    x, _ = jax.lax.scan(functools.partial(_block, cfg=cfg), x, blocks)
+    return x
+
+
+def text_tower_traces() -> int:
+    """Entries in :func:`text_stack`'s compile cache — one per (cfg, token
+    shape) called eagerly; calls under an outer ``jit``/``grad`` add none."""
+    return text_stack._cache_size()
+
+
 def encode_text(p: Params, cfg: ModelConfig, tokens: jax.Array
                 ) -> Tuple[jax.Array, jax.Array]:
     """tokens (B,L) int32 (byte+2; 257=EOS pad) -> (features (B,L,d), pooled (B,d))."""
     x = jnp.take(p["embed"], tokens, axis=0)
-
-    def body(x, bp):
-        x = x + attn.gqa_full(bp["attn"], cfg,
-                              rms_norm(x, bp["ln1"]))
-        x = x + apply_mlp(bp["mlp"], rms_norm(x, bp["ln2"]), cfg.mlp_kind)
-        return x, None
-
-    x, _ = jax.lax.scan(body, x, p["blocks"])
+    x = text_stack(p["blocks"], cfg, x)
     x = rms_norm(x, p["ln_f"])
     # masked mean pool (pad id 257): far more separable than last-token
     # pooling on short templated prompts (EXPERIMENTS.md notes)
@@ -87,13 +107,8 @@ def encode_image(p: Params, images: jax.Array, dim: int = 256,
     x = x.transpose(0, 1, 3, 2, 4, 5).reshape(B, -1, patch * patch * C)
     x = dot(x, p["patch_in"]) + p["pos"][None]
 
-    def body(x, bp):
-        x = x + attn.gqa_full(bp["attn"], cfg, rms_norm(x, bp["ln1"]),
-                              causal=False)
-        x = x + apply_mlp(bp["mlp"], rms_norm(x, bp["ln2"]), cfg.mlp_kind)
-        return x, None
-
-    x, _ = jax.lax.scan(body, x, p["blocks"])
+    x, _ = jax.lax.scan(functools.partial(_block, cfg=cfg, causal=False),
+                        x, p["blocks"])
     pooled = jnp.mean(rms_norm(x, p["ln_f"]), axis=1)
     return pooled / jnp.linalg.norm(pooled, axis=-1, keepdims=True)
 

@@ -416,6 +416,9 @@ class RequestScheduler:
                            lambda: self._arrival_rate)
         self.metrics.gauge("scheduler_inflight_groups",
                            lambda: len(self.inflight))
+        # compiled text-stack entries (process-wide): one per token shape
+        # submitted, so a run of single-prompt submits reads 1
+        self.metrics.gauge("text_tower_traces", te.text_tower_traces)
         if faults is not None:
             self.metrics.attach_family("faults_injected",
                                        faults.injected, "kind")
@@ -465,7 +468,7 @@ class RequestScheduler:
     def _embed(self, prompts: Sequence[str]):
         tr = self.tracer
         if tr is not None:
-            # host lowering + dispatch of the (eager) text tower
+            # host tokenize + dispatch of the text tower
             tr.begin("submit.dispatch", n=len(prompts))
         toks = te.tokenize(prompts, max_len=self.cfg.cond_len)
         feats, pooled = te.encode_text(self.text_params, self.text_cfg, toks)

@@ -466,6 +466,21 @@ def test_streaming_singleton_launches_after_max_wait():
     assert s["completed"] == 1 and s["latency_p50"] > 0
 
 
+def test_single_prompt_submits_compile_text_stack_once():
+    sage = SageConfig(total_steps=4, share_ratio=0.25, guidance_scale=2.0,
+                      tau_min=0.2)
+    sched = RequestScheduler(CFG, sage, PARAMS, TEXT_PARAMS, TC,
+                             group_size=4, slice_steps=2)
+    te.text_stack.clear_cache()
+    prompts = _wave_prompts(20)
+    for i, p in enumerate(prompts):
+        sched.submit([p], now=float(i))
+    assert sched.metrics.snapshot()["text_tower_traces"] == 1
+    sched.submit(prompts[:3], now=20.0)
+    assert sched.metrics.snapshot()["text_tower_traces"] == 2
+    assert sched.stats["requests"] == 23
+
+
 def test_streaming_deadline_forces_launch():
     """An approaching (still meetable) deadline launches a sub-full group
     ahead of ``max_wait_ticks``.  An *already-expired* deadline no longer
